@@ -14,9 +14,11 @@ from repro.core.decode import sampling_decode
 from repro.core.heuristics import solve_ils
 from repro.core.objective import makespan_np
 from repro.core.policy import corais_apply
+from repro.platform import setup_compile_cache
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--en", type=int, default=10)
     ap.add_argument("--rn", type=int, default=100)

@@ -14,9 +14,11 @@ import numpy as np
 from benchmarks.common import csv_line, eval_instances, get_trained_policy
 from repro.core.decode import greedy_decode
 from repro.core.policy import corais_apply
+from repro.platform import setup_compile_cache
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batches", type=int, default=800)
     ap.add_argument("--scales", type=str, default="5x50,10x100,30x400,50x800")

@@ -50,6 +50,7 @@ from repro.core import InstanceConfig, generate_batch, generate_instance
 from repro.core.inference import make_decision_fn, policy_decide
 from repro.core.policy import (PolicyConfig, corais_encode, corais_init,
                                list_score_backends)
+from repro.platform import interpret_mode, setup_compile_cache
 from repro.serving.fastpath import (DEFAULT_BUCKETS, DecisionFastPath,
                                     SLOSpec, evaluate_slo)
 
@@ -219,7 +220,7 @@ def run(backends, scales, *, d_model: int, batch: int, reps: int,
             "decodes": list(decodes),
             "d_model": d_model, "batch": batch, "reps": reps,
             "device": jax.devices()[0].platform,
-            "pallas_interpret": jax.default_backend() != "tpu",
+            "pallas_interpret": interpret_mode(),
         },
         "cells": cells,
     }
@@ -261,7 +262,7 @@ def run_fastpath(*, d_model: int, reps: int, slo: SLOSpec,
                        "p99": slo.p99_ms},
             "buckets": [list(b) for b in buckets],
             "device": jax.devices()[0].platform,
-            "pallas_interpret": jax.default_backend() != "tpu",
+            "pallas_interpret": interpret_mode(),
         },
         "paths": paths,
         "pass": all(p["pass"] for p in paths),
@@ -269,6 +270,7 @@ def run_fastpath(*, d_model: int, reps: int, slo: SLOSpec,
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backends", default="xla,ref,pallas",
                     help=f"comma list from: {','.join(list_score_backends())}")

@@ -33,6 +33,7 @@ import time
 import jax
 import numpy as np
 
+from repro.platform import setup_compile_cache
 from repro.serving import (ASSIGN_FNS, CentralController, EngineConfig,
                            MultiEdgeSim, SimConfig, init_batch, make_rollout,
                            resolve_assign_fn, summarize)
@@ -159,6 +160,7 @@ def bench_fleet(name: str, backend: str, num_edges: int, rounds: int,
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scenario", default="uniform_iid")
     ap.add_argument("--backend", default="greedy", choices=BACKENDS)

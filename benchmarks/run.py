@@ -12,6 +12,9 @@ import time
 
 
 def main() -> None:
+    from repro.platform import setup_compile_cache
+
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--batches", type=int, default=800)

@@ -73,6 +73,7 @@ if _ROOT not in sys.path:
 
 import jax
 
+from repro.platform import setup_compile_cache
 from repro.resilience import faults as faults_lib
 from repro.serving import (ASSIGN_FNS, CentralController, EngineConfig,
                            MultiEdgeSim, SimConfig, init_batch,
@@ -330,6 +331,7 @@ def run_sweep(scenarios: list[str], backends: list[str], *, num_edges: int = 5,
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scenarios", default="all",
                     help="comma list, or 'all' for the full registry")

@@ -13,6 +13,7 @@ import numpy as np
 from benchmarks.common import csv_line, eval_instances, get_trained_policy
 from repro.core.evaluate import evaluate_methods, standard_method_suite
 from repro.core.policy import PolicyConfig
+from repro.platform import setup_compile_cache
 
 
 def run(en=5, rn=50, n_instances=20, batches=800, ref_budget=1.0,
@@ -45,6 +46,7 @@ def run(en=5, rn=50, n_instances=20, batches=800, ref_budget=1.0,
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="all four paper scales + ablations")

@@ -7,6 +7,7 @@ import argparse
 
 from benchmarks.common import csv_line, eval_instances, get_trained_policy
 from repro.core.evaluate import evaluate_methods, standard_method_suite
+from repro.platform import setup_compile_cache
 
 
 def run(train_scale=(5, 50), test_scales=((10, 100), (15, 150)),
@@ -32,6 +33,7 @@ def run(train_scale=(5, 50), test_scales=((10, 100), (15, 150)),
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--instances", type=int, default=10)
     ap.add_argument("--batches", type=int, default=800)

@@ -22,6 +22,7 @@ from benchmarks.common import csv_line, get_trained_policy
 from repro.core.decode import sampling_decode
 from repro.core.objective import per_edge_times
 from repro.core.policy import corais_apply
+from repro.platform import setup_compile_cache
 
 
 def _base_instance(q=5, z=50):
@@ -80,6 +81,7 @@ def run(kind: str, params, state, pcfg, trials=200, sample_n=128, z=50):
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--trials", type=int, default=100)
     ap.add_argument("--batches", type=int, default=800)

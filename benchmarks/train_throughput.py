@@ -51,6 +51,7 @@ from repro.core.train import (TemporalRLConfig, _cluster_seeds,
                               make_temporal_train_step,
                               resolve_temporal_config)
 from repro.optim import AdamConfig, adam_init
+from repro.platform import setup_compile_cache
 from repro.resilience import faults as faults_lib
 from repro.serving import engine as engine_lib
 from repro.serving.engine import EngineConfig
@@ -174,6 +175,7 @@ def run_cell(mode: str, cfg: TemporalRLConfig, *, updates: int, warmup: int,
 
 
 def main() -> int:
+    setup_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--scenarios", default="uniform_iid,chaos-rolling-failure")
     ap.add_argument("--modes", default="host-loop,scan-epoch,sharded")

@@ -19,6 +19,12 @@ import jax.numpy as jnp
 import numpy as np
 
 NEG = -1e9
+#: The per-edge sums below are one-hot contractions that XLA lowers to dots.
+#: They are bookkeeping, not a network layer: on the TPU the default f32 dot
+#: precision (one bf16 pass) would round the per-edge loads mu and eta to
+#: ~3 significant digits, so they run at full f32 precision (a no-op on the
+#: CPU).
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def phi_eval(phi, sizes):
@@ -42,13 +48,15 @@ def per_edge_times(inst, assign):
     comp = phi_eval(inst["phi"], sizes)  # (..., Z, Q)
     # eq (5): locally-executed new work + local backlog
     mu = (
-        jnp.einsum("...zq,...zq->...q", onehot * local[..., None], comp)
+        jnp.einsum("...zq,...zq->...q", onehot * local[..., None], comp,
+                   precision=_EXACT)
         / inst["replicas"]
         + inst["workload"][..., 0]
     )
     # eq (6): transferred-in new work + transferred-in backlog
     eta = (
-        jnp.einsum("...zq,...zq->...q", onehot * (1.0 - local[..., None]), comp)
+        jnp.einsum("...zq,...zq->...q", onehot * (1.0 - local[..., None]), comp,
+                   precision=_EXACT)
         / inst["replicas"]
         + inst["workload"][..., 1]
     )

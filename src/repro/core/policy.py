@@ -255,7 +255,12 @@ def _encoder_apply(layers, states, x, mask, cfg: PolicyConfig, training: bool):
 
 
 def _masked_max(x, mask):
-    return jnp.max(jnp.where(mask[..., None], x, -jnp.inf), axis=-2)
+    """Max over the valid rows; zeros where no row is valid (a round with
+    no arrivals). A -inf there would enter the context query, and although
+    the masked attention hides it from the forward, its weight gradient is
+    -inf * 0 = NaN."""
+    m = jnp.max(jnp.where(mask[..., None], x, -jnp.inf), axis=-2)
+    return jnp.where(jnp.any(mask, axis=-1)[..., None], m, 0.0)
 
 
 def corais_encode(params, state, inst, cfg: PolicyConfig, *,

@@ -26,6 +26,7 @@ from repro.core.objective import makespan
 from repro.core.policy import (PolicyConfig, corais_admit, corais_encode,
                                corais_init, corais_score)
 from repro.optim import AdamConfig, adam_init, adam_update, clip_by_global_norm
+from repro.platform import donate_default
 from repro.resilience import faults as faults_lib
 from repro.resilience.policies import nearest_alive
 from repro.serving import engine as engine_lib
@@ -471,7 +472,7 @@ def make_temporal_epoch_step(cfg: TemporalRLConfig,
     compile_device_plan(wl, ecfg.num_edges, ecfg.num_rounds,
                         ecfg.round_interval)
     if donate is None:
-        donate = jax.default_backend() != "cpu"
+        donate = donate_default()
     axis_name = axis if mesh is not None else None
 
     def epoch(params, policy_state, opt_state, sim0, elem_keys):
@@ -500,10 +501,6 @@ def make_temporal_epoch_step(cfg: TemporalRLConfig,
     if mesh is None:
         return jax.jit(epoch, donate_argnums=donate_args), adam_cfg
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:  # jax < 0.5
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     cache: dict = {}
@@ -515,14 +512,14 @@ def make_temporal_epoch_step(cfg: TemporalRLConfig,
             batched = lambda x: PartitionSpec(  # noqa: E731
                 None, axis, *(None,) * (x.ndim - 2))
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     epoch, mesh=mesh,
                     in_specs=(PartitionSpec(), PartitionSpec(),
                               PartitionSpec(), jax.tree.map(batched, sim0),
                               PartitionSpec(None, axis, None)),
                     out_specs=(PartitionSpec(), PartitionSpec(),
                                PartitionSpec()),
-                    check_rep=False),
+                    check_vma=False),
                 donate_argnums=donate_args)
             cache[sig] = fn
         return fn(params, policy_state, opt_state, sim0, elem_keys)

@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``INTERPRET`` defaults to True off-TPU: the kernel bodies execute in Python
-(emulation) for correctness validation on CPU, and compile to Mosaic on a
-real TPU. The pure-jnp oracles live in ref.py; tests sweep shapes/dtypes and
+Interpret mode is decided by :func:`repro.platform.interpret_mode`: the
+kernel bodies execute in the Pallas interpreter on the CPU (correctness
+validation) and compile to Mosaic on a TPU. The pure-jnp oracles live in ref.py; tests sweep shapes/dtypes and
 assert allclose kernel-vs-ref.
 """
 from __future__ import annotations
@@ -17,11 +17,7 @@ from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.mamba_scan import mamba_scan_fwd
 from repro.kernels.policy_score import (policy_score_decode_fwd,
                                         policy_score_fwd)
-
-def interpret_mode() -> bool:
-    """Lazy: avoids initializing the jax backend at import time (the dry-run
-    must set XLA_FLAGS before anything touches jax device state)."""
-    return jax.default_backend() != "tpu"
+from repro.platform import interpret_mode
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "bq", "bk"))
@@ -64,4 +60,4 @@ def policy_score_decode(c_emb, h_emb, w_px, w_py, edge_mask, *,
 
 
 __all__ = ["flash_attention", "decode_attention", "mamba_scan",
-           "policy_score", "policy_score_decode", "ref", "interpret_mode"]
+           "policy_score", "policy_score_decode", "ref"]

@@ -56,7 +56,7 @@ def _fwd_kernel(c_ref, h_ref, wpx_ref, wpy_ref, mask_ref, o_ref, *,
     py = jax.lax.dot(h, wpy_ref[...].astype(jnp.float32))   # (bz, d)
     u = jax.lax.dot_general(py, px, (((1,), (1,)), ((), ()))) * scale  # (bz, Q)
     imp = tanh_clip * jnp.tanh(u)
-    imp = jnp.where(mask_ref[0][None, :] > 0.5, imp, -1e9)
+    imp = jnp.where(mask_ref[0] > 0.5, imp, -1e9)
     m = jnp.max(imp, axis=1, keepdims=True)
     lse = jnp.log(jnp.sum(jnp.exp(imp - m), axis=1, keepdims=True)) + m
     o_ref[0] = (imp - lse).astype(o_ref.dtype)
@@ -71,7 +71,7 @@ def _bwd_kernel(g_ref, o_ref, c_ref, h_ref, wpx_ref, wpy_ref, mask_ref,
     h = h_ref[0].astype(jnp.float32)          # (Z, d)
     wx = wpx_ref[...].astype(jnp.float32)
     wy = wpy_ref[...].astype(jnp.float32)
-    keep = mask_ref[0][None, :] > 0.5         # (1, Q)
+    keep = mask_ref[0] > 0.5                  # (1, Q)
 
     # d log_softmax: g - softmax * sum_q g  (softmax = exp(saved log-probs))
     gi = g - jnp.exp(out) * jnp.sum(g, axis=1, keepdims=True)
@@ -89,6 +89,15 @@ def _bwd_kernel(g_ref, o_ref, c_ref, h_ref, wpx_ref, wpy_ref, mask_ref,
     dh_ref[0] = jax.lax.dot_general(dpy, wy, (((1,), (1,)), ((), ())))
     dwx_ref[0] = jax.lax.dot_general(c, dpx, (((0,), (0,)), ((), ())))
     dwy_ref[0] = jax.lax.dot_general(h, dpy, (((0,), (0,)), ((), ())))
+
+
+def _mask3(edge_mask, batch_shape, q: int):
+    """Edge mask as a (B, 1, Q) float array. Its (1, 1, Q) block keeps the
+    last two block dimensions equal to the array's, which the TPU lowering
+    requires for every B (a (1, Q) block of a (B, Q) array is refused
+    whenever B > 1)."""
+    maskf = jnp.broadcast_to(edge_mask, batch_shape + (q,))
+    return maskf.reshape((-1, 1, q)).astype(jnp.float32)
 
 
 def _pad_z(x, bz: int):
@@ -122,7 +131,7 @@ def _policy_score_fwd(c_emb, h_emb, w_px, w_py, maskf, tanh_clip, bz,
             pl.BlockSpec((1, bz, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((d, d), lambda i, j: (0, 0)),
             pl.BlockSpec((d, d), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, q), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, q), lambda i, j: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bz, q), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hp.shape[1], q), jnp.float32),
@@ -153,7 +162,7 @@ def _policy_score_bwd(tanh_clip, bz, interpret, res, g):
             pl.BlockSpec((1, zp, d), lambda i: (i, 0, 0)),
             pl.BlockSpec((d, d), lambda i: (0, 0)),
             pl.BlockSpec((d, d), lambda i: (0, 0)),
-            pl.BlockSpec((1, q), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, q), lambda i: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, q, d), lambda i: (i, 0, 0)),
@@ -187,7 +196,7 @@ def _decode_kernel(c_ref, h_ref, wpx_ref, wpy_ref, mask_ref, ti_ref, tv_ref,
     # matmul is the only one that touches the Z axis
     pxy = jax.lax.dot(wpy_ref[...].astype(jnp.float32), px.T)
     u = jax.lax.dot(hh, pxy) * scale                         # (bz, Q)
-    keep = mask_ref[0][None, :] > 0.5
+    keep = mask_ref[0] > 0.5                                 # (1, Q)
     qn = u.shape[1]
     ids = jax.lax.broadcasted_iota(jnp.int32, u.shape, 1)
     if normalize:
@@ -236,8 +245,7 @@ def policy_score_decode_fwd(c_emb, h_emb, w_px, w_py, edge_mask, *,
     z = h_emb.shape[-2]
     c3 = c_emb.reshape((-1, q, d))
     h3 = h_emb.reshape((-1, z, d))
-    maskf = jnp.broadcast_to(edge_mask, batch_shape + (q,))
-    maskf = maskf.reshape((-1, q)).astype(jnp.float32)
+    maskf = _mask3(edge_mask, batch_shape, q)
     b = c3.shape[0]
     bz = min(bz, z)
     hp = _pad_z(h3, bz)
@@ -252,7 +260,7 @@ def policy_score_decode_fwd(c_emb, h_emb, w_px, w_py, edge_mask, *,
             pl.BlockSpec((1, bz, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((d, d), lambda i, j: (0, 0)),
             pl.BlockSpec((d, d), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, q), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, q), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bz, k), lambda i, j: (i, j, 0)),
@@ -284,8 +292,7 @@ def policy_score_fwd(c_emb, h_emb, w_px, w_py, edge_mask, *,
     z = h_emb.shape[-2]
     c3 = c_emb.reshape((-1, q, d))
     h3 = h_emb.reshape((-1, z, d))
-    maskf = jnp.broadcast_to(edge_mask, batch_shape + (q,))
-    maskf = maskf.reshape((-1, q)).astype(jnp.float32)
+    maskf = _mask3(edge_mask, batch_shape, q)
     out = _policy_score(c3, h3, w_px, w_py, maskf,
                         float(tanh_clip), int(bz), bool(interpret))
     return out.reshape(batch_shape + (z, q))

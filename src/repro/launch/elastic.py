@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro.checkpoint import Checkpointer
 from repro.configs import get_reduced_config
 from repro.data.synthetic import SyntheticTokens
+from repro.launch.mesh import auto_mesh
 from repro.models import lm
 from repro.optim import AdamConfig, adam_init, adam_update
 from repro.sharding import specs as S
@@ -33,7 +34,7 @@ from repro.sharding import specs as S
 
 def run_phase(phase: str, mesh_shape, steps: int, ckpt_dir: str, arch: str):
     cfg = get_reduced_config(arch)
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = auto_mesh(mesh_shape, ("data", "model"))
     adam = AdamConfig(lr=1e-3)
     key = jax.random.PRNGKey(0)
     params_shapes = jax.eval_shape(lambda: lm.init_params(key, cfg))

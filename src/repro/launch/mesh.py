@@ -8,14 +8,21 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the program places arrays with
+    ``with_sharding_constraint`` and shard_map, which Explicit axes (the
+    default of ``make_mesh``) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi-pod = 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1,
@@ -30,7 +37,7 @@ def make_host_mesh(model_parallel: int = 1,
         raise ValueError(
             f"cannot build a host mesh: {n} available device(s) not "
             f"divisible by model_parallel={model_parallel}")
-    return jax.make_mesh((n // model_parallel, model_parallel), axis_names)
+    return auto_mesh((n // model_parallel, model_parallel), axis_names)
 
 
 def make_fleet_mesh(num_shards: int | None = None, *, dry_run: bool = False):

@@ -21,11 +21,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # pre-0.5 jax keeps it in jax.experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 NEG_INF = -1e30
 
 
@@ -303,7 +298,7 @@ def sharded_decode_attention(q, k_cache, v_cache, cache_positions, pos, *,
         return out.reshape(-1, H, hd).astype(q.dtype)
 
     mesh = ctx.mesh
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(dp, None, None), P(dp, tp, None, None),

@@ -50,6 +50,7 @@ import numpy as np
 
 from repro.core.inference import DecisionSpec, make_decision_fn
 from repro.core.policy import PolicyConfig
+from repro.platform import donate_default
 
 #: (q_pad, z_pad) ladder covering the paper's serving grid (Q <= 100 edges,
 #: Z <= 1000 requests/round). A snapshot lands in the smallest bucket that
@@ -135,7 +136,7 @@ class DecisionFastPath:
                  backend: Optional[str] = None,
                  donate: Optional[bool] = None, seed: int = 0):
         if donate is None:
-            donate = jax.default_backend() != "cpu"
+            donate = donate_default()
         if spec is None:
             if normalize is None:
                 # the normalizer cannot move a greedy argmax; sampling

@@ -37,11 +37,6 @@ import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pre-0.5 jax keeps it in jax.experimental
-    from jax.experimental.shard_map import shard_map
-
 from repro.serving import engine
 from repro.sharding.specs import arrival_specs, engine_state_specs
 from repro.workloads.base import edge_weights
@@ -182,8 +177,8 @@ def make_fleet_rollout(cfg: engine.EngineConfig, assign_fn, mesh, *,
             in_specs = (engine_state_specs(states, axis),
                         arrival_specs(arrivals, axis),
                         arrival_specs(keys, axis), P(axis))
-            fn = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                                   out_specs=P(), check_rep=False))
+            fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                       out_specs=P(), check_vma=False))
             cache[sig] = fn
         return fn(states, arrivals, keys, displaced)
 

@@ -11,14 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pre-0.5 jax keeps it in jax.experimental
-    from jax.experimental.shard_map import shard_map
-
 from repro.configs import get_reduced_config
 from repro.configs.base import ShapeConfig
 from repro.data.synthetic import SyntheticTokens
+from repro.launch.mesh import auto_mesh
 from repro.launch.steps import TrainKnobs, build_train_step
 from repro.models import lm
 from repro.optim import AdamConfig, adam_init, adam_update, clip_by_global_norm
@@ -26,14 +22,14 @@ from repro.optim.grad_utils import compressed_psum
 
 
 def check_compressed_psum():
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = auto_mesh((8,), ("data",))
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 64)) * 3.0
 
     def body(xs):
         return compressed_psum(xs, "data", 8)
 
-    out = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data", None),
-                            out_specs=P("data", None)))(x)
+    out = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data", None),
+                                out_specs=P("data", None)))(x)
     expected = jnp.broadcast_to(jnp.sum(x, axis=0, keepdims=True), x.shape)
     err = float(jnp.abs(out - expected).max())
     # int8 absmax quantization: per-element error <= shards * scale/2
@@ -45,7 +41,7 @@ def check_compressed_psum():
 def check_sharded_train_equivalence():
     cfg = get_reduced_config("olmo-1b")
     shape = ShapeConfig("tiny_train", seq_len=32, global_batch=8, kind="train")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     knobs = TrainKnobs(lr=1e-2, donate=False)
     step, _, _ = build_train_step(cfg, mesh, shape, knobs)
 

@@ -15,8 +15,6 @@ def test_xla_flop_convention_is_2mnk():
     low = f.lower(jax.ShapeDtypeStruct((256, 512), jnp.float32),
                   jax.ShapeDtypeStruct((512, 128), jnp.float32))
     ca = low.compile().cost_analysis()
-    if isinstance(ca, list):  # pre-0.5 jax returns one dict per computation
-        ca = ca[0]
     assert ca["flops"] == pytest.approx(2 * 256 * 512 * 128, rel=0.01)
 
 
