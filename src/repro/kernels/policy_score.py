@@ -37,6 +37,10 @@ cheaper than score-then-argmax even before the HBM traffic is counted:
     the K selected values only, skipping the (Z, Q) transcendental sweep
     and the log-softmax normalizer entirely. ``normalize=True`` keeps the
     eq-17 semantics and emits true log-probabilities.
+
+Each ``pallas_call`` carries an explicit name, which a TPU profiler trace
+gives its operation whatever jit encloses it: ``policy_score_fwd``,
+``policy_score_bwd`` and ``policy_score_decode``.
 """
 from __future__ import annotations
 
@@ -136,6 +140,7 @@ def _policy_score_fwd(c_emb, h_emb, w_px, w_py, maskf, tanh_clip, bz,
         out_specs=pl.BlockSpec((1, bz, q), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hp.shape[1], q), jnp.float32),
         interpret=interpret,
+        name="policy_score_fwd",
     )(c_emb, hp, w_px, w_py, maskf)
     out = out[:, :z]
     return out, (c_emb, h_emb, w_px, w_py, maskf, out)
@@ -177,6 +182,7 @@ def _policy_score_bwd(tanh_clip, bz, interpret, res, g):
             jax.ShapeDtypeStruct((b, d, d), jnp.float32),
         ],
         interpret=interpret,
+        name="policy_score_bwd",
     )(gp, op, c_emb, hp, w_px, w_py, maskf)
     return (dc.astype(c_emb.dtype), dh[:, :z].astype(h_emb.dtype),
             jnp.sum(dwx, 0).astype(w_px.dtype),
@@ -271,6 +277,7 @@ def policy_score_decode_fwd(c_emb, h_emb, w_px, w_py, edge_mask, *,
             jax.ShapeDtypeStruct((b, hp.shape[1], k), jnp.float32),
         ],
         interpret=interpret,
+        name="policy_score_decode",
     )(c3, hp, w_px, w_py, maskf)
     ti = ti[:, :z].reshape(batch_shape + (z, k))
     tv = tv[:, :z].reshape(batch_shape + (z, k))

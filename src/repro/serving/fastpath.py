@@ -51,6 +51,7 @@ import numpy as np
 from repro.core.inference import DecisionSpec, make_decision_fn
 from repro.core.policy import PolicyConfig
 from repro.platform import donate_default
+from repro.tracing import span
 
 #: (q_pad, z_pad) ladder covering the paper's serving grid (Q <= 100 edges,
 #: Z <= 1000 requests/round). A snapshot lands in the smallest bucket that
@@ -157,7 +158,6 @@ class DecisionFastPath:
         self._round = 0
         self._key0 = jax.random.PRNGKey(seed)
         self.compile_ms: dict[tuple[int, int], float] = {}
-        self.latencies_ms: list[float] = []
 
     # -- bucket machinery ---------------------------------------------------
 
@@ -228,29 +228,48 @@ class DecisionFastPath:
 
     def submit(self, inst: dict):
         """Stage + dispatch one decision; returns an in-flight handle
-        (jax async dispatch — the host is free as soon as this returns)."""
-        q = int(np.shape(inst["edge_mask"])[-1])
-        z = int(np.shape(inst["req_mask"])[-1])
-        bucket = self.bucket_for(q, z)
-        fn = self._get_fn(bucket)
-        staged = self._stage(inst, bucket)
-        dev = jax.device_put(staged)
-        out = fn(dev, self._round_key())
-        self._round += 1
-        return out, z
+        (jax async dispatch — the host is free as soon as this returns).
+
+        Under a profiler session the call is the host span
+        ``corais.fastpath.submit`` (arguments ``round``, the decision
+        counter, and ``q``, ``z``, ``q_pad``, ``z_pad``) holding
+        ``corais.fastpath.stage`` (bucket, padding, ping-pong copy),
+        ``.transfer`` (``device_put``) and ``.dispatch`` (the call of the
+        compiled decision program)."""
+        n = self._round
+        with span("fastpath.submit", round=n) as sp:
+            with span("fastpath.stage"):
+                q = int(np.shape(inst["edge_mask"])[-1])
+                z = int(np.shape(inst["req_mask"])[-1])
+                bucket = self.bucket_for(q, z)
+                fn = self._get_fn(bucket)
+                staged = self._stage(inst, bucket)
+            sp.set_metadata(q=q, z=z, q_pad=bucket[0], z_pad=bucket[1])
+            with span("fastpath.transfer"):
+                dev = jax.device_put(staged)
+            with span("fastpath.dispatch"):
+                out = fn(dev, self._round_key())
+            self._round += 1
+        return out, z, n
 
     def result(self, handle) -> np.ndarray:
         """Block on an in-flight decision; returns the (z,) int32 assignment
-        with bucket padding stripped."""
-        out, z = handle
-        return np.asarray(jax.block_until_ready(out))[:z]
+        with bucket padding stripped.
+
+        Under a profiler session the call is the host span
+        ``corais.fastpath.result`` (argument ``round``, that of its
+        ``submit``) holding ``corais.fastpath.wait`` (until the device is
+        done) and ``.fetch`` (the copy to the host and the strip)."""
+        out, z, n = handle
+        with span("fastpath.result", round=n):
+            with span("fastpath.wait"):
+                jax.block_until_ready(out)
+            with span("fastpath.fetch"):
+                return np.asarray(out)[:z]
 
     def decide(self, inst: dict) -> np.ndarray:
-        """Synchronous submit+result, recording wall latency (ms)."""
-        t0 = time.perf_counter()
-        assign = self.result(self.submit(inst))
-        self.latencies_ms.append((time.perf_counter() - t0) * 1e3)
-        return assign
+        """Synchronous submit+result."""
+        return self.result(self.submit(inst))
 
     def stream(self, insts: Iterable[dict]):
         """Pipelined decision stream: round n+1 is staged and dispatched
@@ -273,8 +292,8 @@ def evaluate_slo(fastpath: DecisionFastPath, insts: Sequence[dict],
     Replays ``insts`` through :meth:`DecisionFastPath.decide` (after
     warming exactly the padding buckets the workload will hit, plus
     ``warmup_rounds`` unmeasured decide passes per hit bucket to absorb
-    dispatch-path warmup), then evaluates ``slo`` on the recorded wall
-    latencies. Returns the :meth:`SLOSpec.check` report plus
+    dispatch-path warmup), then evaluates ``slo`` on the wall latencies of
+    the measured passes. Returns the :meth:`SLOSpec.check` report plus
     bucket/compile metadata.
     """
     if not insts:
@@ -291,14 +310,15 @@ def evaluate_slo(fastpath: DecisionFastPath, insts: Sequence[dict],
     cold = [b for b in first_in_bucket if b not in fastpath.compile_ms]
     if cold:
         fastpath.warmup(cold)
-    before = len(fastpath.latencies_ms)
     for inst in first_in_bucket.values():
         for _ in range(warmup_rounds):
             fastpath.decide(inst)
-    del fastpath.latencies_ms[before:]
+    latencies_ms = []
     for inst in insts:
+        t0 = time.perf_counter()
         fastpath.decide(inst)
-    report = slo.check(fastpath.latencies_ms[before:])
+        latencies_ms.append((time.perf_counter() - t0) * 1e3)
+    report = slo.check(latencies_ms)
     report["buckets"] = [list(b) for b in fastpath.buckets]
     report["compile_ms"] = {f"{b[0]}x{b[1]}": ms
                             for b, ms in fastpath.compile_ms.items()}

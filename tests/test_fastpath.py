@@ -3,6 +3,7 @@ SLO evaluation, and the drift-check schema compatibility."""
 import importlib.util
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -98,8 +99,9 @@ def test_fastpath_warmup_compiles_buckets(policy):
     assert all(ms > 0 for ms in compile_ms.values())
     # warmed executables answer without recompiling (latency way under
     # compile time)
+    t0 = time.perf_counter()
     fp.decide(_inst(5, 20))
-    assert fp.latencies_ms[-1] < compile_ms[(8, 32)]
+    assert (time.perf_counter() - t0) * 1e3 < compile_ms[(8, 32)]
 
 
 def test_fastpath_modes_and_donation_default(policy):

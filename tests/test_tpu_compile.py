@@ -9,6 +9,7 @@ executable. The topology is described inside a fixture, never at import:
 only one process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,3 +60,28 @@ def _decode(c, h, wx, wy, m):
 def test_policy_score_compiles_for_v5e(one_chip, fn, b, q, z):
     compiled = jax.jit(fn).lower(*_args(one_chip, b, q, z)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _vmap_decode(c, h, wx, wy, m):
+    # as the batched rollout calls it: per instance, under vmap
+    return jax.vmap(lambda c, h, m: policy_score_decode_fwd(
+        c, h, wx, wy, m, interpret=False))(c, h, m)
+
+
+@pytest.mark.parametrize("fn,names", [
+    (_fwd, ["policy_score_fwd"]),
+    (_grad, ["policy_score_bwd", "policy_score_fwd"]),
+    (_decode, ["policy_score_decode"]),
+    (_vmap_decode, ["policy_score_decode"]),
+], ids=["forward", "grad", "decode", "vmap-decode"])
+def test_policy_kernels_name_their_tpu_ops(one_chip, fn, names):
+    """A TPU trace names a Pallas kernel's operation after its HLO
+    instruction, which holds the kernel's explicit ``name`` whatever
+    encloses it (under ``grad`` or ``vmap``, wrapped as ``jvp_...`` or
+    ``vmap_...``): the name the benchmark's roofline readers match."""
+    text = jax.jit(fn).lower(*_args(one_chip, 2, 10, 100)).compile().as_text()
+    got = re.findall(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                     text)
+    assert len(got) == len(names), got
+    for name in names:
+        assert sum(name in g for g in got) == 1, got
