@@ -123,9 +123,9 @@ def phase_decision_server(params, pstate, pcfg, seed):
         if backend == "pallas":
             for bucket in DEFAULT_BUCKETS:
                 inst = next(i for b, i in work if b == bucket)
-                staged = jax.device_put(pad_instance(inst, *bucket))
-                compiled_with_kernel(fp._get_fn(bucket), staged, fp._key0,
-                                     what=f"pallas bucket {bucket}")
+                lane, packed = fp._stage(inst, bucket)
+                compiled_with_kernel(lane.fn, jax.device_put(packed),
+                                     fp._key0, what=f"pallas bucket {bucket}")
         equal = total = 0
         worst = 0.0
         for (bucket, inst), lp in zip(work, ref):

@@ -22,15 +22,19 @@ fused in-kernel decode
     ``normalize=False``: the log-softmax normalizer cannot change an
     argmax, so serving skips it.
 
-double-buffered staging + donated device buffers
-    :meth:`submit` stages the padded snapshot into one of two host-side
-    numpy buffer sets (ping-pong), ships it, and returns immediately with
-    the decision still in flight (jax dispatch is async); :meth:`result`
-    blocks. Staging round n+1 therefore never overwrites host memory an
-    in-flight transfer of round n may still be reading. With ``donate=True``
-    (default off-CPU; CPU jax does not support donation) the instance
-    buffers are donated to the call, so XLA reuses the same device memory
-    round after round instead of allocating per decision.
+one packed transfer per round
+    :meth:`submit` packs the padded snapshot into one flat int32 host
+    buffer (:class:`PackedLayout`: each leaf in its own 128-word-aligned
+    segment, float32 bit for bit, masks as 0/1), ships it with a single
+    ``device_put`` and returns with the decision still in flight (jax
+    dispatch is async); :meth:`result` blocks. The compiled decision
+    program unpacks the buffer on the device. A ``device_put`` pays a fixed
+    runtime cost per leaf, whatever its size, so one leaf in place of ten
+    is what the packing saves. Each bucket keeps two such buffers
+    (ping-pong), allocated once and reused: staging round n+1 never
+    overwrites host memory an in-flight transfer of round n may still be
+    reading. With ``donate=True`` (default off-CPU; CPU jax does not
+    support donation) the packed buffer is donated to the call.
 
 explicit SLOs
     :class:`SLOSpec` states the latency contract (p50/p95/p99 in ms);
@@ -41,14 +45,16 @@ explicit SLOs
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from typing import Iterable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
-from repro.core.inference import DecisionSpec, make_decision_fn
+from repro.core.inference import DecisionSpec, policy_decide
 from repro.core.policy import PolicyConfig
 from repro.platform import donate_default
 from repro.tracing import span
@@ -83,6 +89,95 @@ def pad_instance(inst: dict, q_pad: int, z_pad: int) -> dict:
     return out
 
 
+#: Every segment of a packed buffer starts at a multiple of this many
+#: 32-bit words, so each slice on the device starts on a lane boundary.
+_ALIGN_WORDS = 128
+_PACKABLE = ("float32", "int32", "bool")
+
+
+@dataclasses.dataclass(frozen=True)
+class PackedLayout:
+    """Where each leaf of a padded instance lives in one flat int32 buffer.
+
+    ``leaves`` holds ``(key, shape, dtype name)`` in key order and
+    ``offsets`` the word each leaf's segment starts at (a multiple of
+    128); ``words`` is the buffer's length. float32 leaves are stored bit
+    for bit through an int32 view (never as floats: an int32 read as a
+    float32 may be a denormal), int32 leaves as they are, bool leaves as
+    0/1. Built from the tree :func:`pad_instance` returns, so an instance
+    that carries extra keys gets a layout of its own."""
+
+    leaves: tuple[tuple[str, tuple[int, ...], str], ...]
+    offsets: tuple[int, ...]
+    words: int
+
+    @classmethod
+    def of(cls, padded: dict) -> "PackedLayout":
+        leaves, offsets, end = [], [], 0
+        for k in sorted(padded):
+            a = np.asarray(padded[k])
+            dtype = jax.dtypes.canonicalize_dtype(a.dtype).name
+            if dtype not in _PACKABLE:
+                raise TypeError(f"instance leaf {k!r} has dtype {dtype}; "
+                                f"the packed stage holds {_PACKABLE}")
+            leaves.append((k, a.shape, dtype))
+            offsets.append(end)
+            end += -(-a.size // _ALIGN_WORDS) * _ALIGN_WORDS
+        return cls(tuple(leaves), tuple(offsets), end)
+
+    @property
+    def nbytes(self) -> int:
+        return 4 * self.words
+
+    def views(self, buf: np.ndarray) -> list[np.ndarray]:
+        """Each leaf's segment of the host buffer ``buf``, shaped, as
+        float32 for float32 leaves and int32 otherwise."""
+        out = []
+        for (_, shape, dtype), off in zip(self.leaves, self.offsets):
+            seg = buf[off:off + math.prod(shape)]
+            out.append((seg.view(np.float32) if dtype == "float32"
+                        else seg).reshape(shape))
+        return out
+
+    def unpack(self, packed: jax.Array) -> dict:
+        """The padded instance back from a packed buffer, in jnp: static
+        slices, reshapes and bitcasts that XLA fuses into their users."""
+        out = {}
+        for (k, shape, dtype), off in zip(self.leaves, self.offsets):
+            seg = lax.slice(packed, (off,), (off + math.prod(shape),))
+            seg = seg.reshape(shape)
+            if dtype == "float32":
+                seg = lax.bitcast_convert_type(seg, jnp.float32)
+            elif dtype == "bool":
+                seg = seg != 0
+            out[k] = seg
+        return out
+
+
+class _Lane:
+    """One packed layout's compiled decision program and its two ping-pong
+    host buffers, allocated once and restaged every round."""
+
+    def __init__(self, layout: PackedLayout, fn):
+        self.layout, self.fn = layout, fn
+        self.bufs = [np.zeros(layout.words, np.int32) for _ in range(2)]
+        self.views = [layout.views(b) for b in self.bufs]
+        self.slot = 0
+
+    def stage(self, inst: dict) -> np.ndarray:
+        """Zero the next ping-pong buffer and write each leaf of ``inst``
+        into the leading corner of its segment; returns the buffer."""
+        slot = self.slot
+        self.slot = 1 - slot
+        buf = self.bufs[slot]
+        buf.fill(0)
+        for (k, _, _), view in zip(self.layout.leaves, self.views[slot]):
+            a = np.asarray(inst[k])
+            np.copyto(view[(*map(slice, a.shape), ...)], a,
+                      casting="same_kind")
+        return buf
+
+
 @dataclasses.dataclass(frozen=True)
 class SLOSpec:
     """Latency contract for one decision path, in milliseconds."""
@@ -114,9 +209,11 @@ class SLOSpec:
 class DecisionFastPath:
     """Compile-once, double-buffered policy decision loop.
 
-    One instance owns, per padding bucket: a jitted decision function
-    (built by :func:`repro.core.inference.make_decision_fn`, fused decode
-    by default) and two ping-pong host staging buffer sets. The round loop
+    One instance owns, per padding bucket (and per :class:`PackedLayout`,
+    should an instance carry extra keys): a jitted decision program that
+    unpacks the packed buffer and runs
+    :func:`repro.core.inference.policy_decide` (fused decode by default),
+    and two ping-pong packed host buffers. The round loop
     is ``submit`` (stage + async dispatch) then ``result`` (block + strip
     padding); :meth:`decide` does both, :meth:`stream` overlaps them one
     round deep.
@@ -152,9 +249,8 @@ class DecisionFastPath:
         self.buckets = tuple(sorted(tuple(b) for b in buckets))
         self.donate = donate
         self._params, self._state, self._cfg = params, policy_state, cfg
-        self._fns: dict[tuple[int, int], object] = {}
-        self._staging: dict[tuple[int, int], list] = {}
-        self._slot: dict[tuple[int, int], int] = {}
+        # (bucket, sorted instance keys) -> lane
+        self._lanes: dict[tuple, _Lane] = {}
         self._round = 0
         self._key0 = jax.random.PRNGKey(seed)
         self.compile_ms: dict[tuple[int, int], float] = {}
@@ -170,31 +266,27 @@ class DecisionFastPath:
             f"snapshot ({q}, {z}) exceeds every fast-path bucket "
             f"{self.buckets}; add a larger bucket")
 
-    def _get_fn(self, bucket):
-        fn = self._fns.get(bucket)
-        if fn is None:
-            fn = make_decision_fn(self._params, self._state, self._cfg,
-                                  self.spec, donate=self.donate)
-            self._fns[bucket] = fn
-            # two host staging pytrees (ping-pong): stage round n+1 while
-            # round n's transfer may still be reading the other set
-            self._staging[bucket] = [None, None]
-            self._slot[bucket] = 0
-        return fn
+    def _decision_fn(self, layout: PackedLayout):
+        params, state, cfg, spec = (self._params, self._state, self._cfg,
+                                    self.spec)
 
-    def _stage(self, inst, bucket):
-        """Pad into this bucket's current ping-pong staging buffers."""
-        slot = self._slot[bucket]
-        self._slot[bucket] = 1 - slot
-        padded = pad_instance(inst, *bucket)
-        buf = self._staging[bucket][slot]
-        if buf is None:
-            buf = {k: np.array(v, copy=True) for k, v in padded.items()}
-            self._staging[bucket][slot] = buf
-        else:
-            for k, v in padded.items():
-                np.copyto(buf[k], v, casting="same_kind")
-        return buf
+        def decide(packed, key):
+            return policy_decide(key, params, state, layout.unpack(packed),
+                                 cfg, spec)
+
+        return jax.jit(decide, donate_argnums=(0,) if self.donate else ())
+
+    def _stage(self, inst: dict, bucket) -> tuple[_Lane, np.ndarray]:
+        """Pack ``inst`` into the next ping-pong host buffer of its bucket
+        and key set; returns the lane (layout and decision program) and
+        the buffer. The first instance of a key set in a bucket derives the
+        layout from :func:`pad_instance`."""
+        sig = (bucket, tuple(sorted(inst)))
+        lane = self._lanes.get(sig)
+        if lane is None:
+            layout = PackedLayout.of(pad_instance(inst, *bucket))
+            lane = self._lanes[sig] = _Lane(layout, self._decision_fn(layout))
+        return lane, lane.stage(inst)
 
     def _round_key(self):
         if self.mode == "greedy":
@@ -208,7 +300,6 @@ class DecisionFastPath:
         of traffic; returns {bucket: compile_ms}."""
         for bucket in (buckets or self.buckets):
             bucket = tuple(bucket)
-            fn = self._get_fn(bucket)
             zero = {
                 "edge_coords": np.zeros((bucket[0], 2), np.float32),
                 "phi": np.zeros((bucket[0], 2), np.float32),
@@ -221,8 +312,9 @@ class DecisionFastPath:
                 "edge_mask": np.arange(bucket[0]) < 1,
                 "req_mask": np.zeros(bucket[1], bool),
             }
+            lane, packed = self._stage(zero, bucket)
             t0 = time.perf_counter()
-            jax.block_until_ready(fn(jax.device_put(zero), self._key0))
+            jax.block_until_ready(lane.fn(jax.device_put(packed), self._key0))
             self.compile_ms[bucket] = (time.perf_counter() - t0) * 1e3
         return dict(self.compile_ms)
 
@@ -233,22 +325,22 @@ class DecisionFastPath:
         Under a profiler session the call is the host span
         ``corais.fastpath.submit`` (arguments ``round``, the decision
         counter, and ``q``, ``z``, ``q_pad``, ``z_pad``) holding
-        ``corais.fastpath.stage`` (bucket, padding, ping-pong copy),
-        ``.transfer`` (``device_put``) and ``.dispatch`` (the call of the
-        compiled decision program)."""
+        ``corais.fastpath.stage`` (bucket, packing into the ping-pong
+        buffer), ``.transfer`` (the one ``device_put``; arguments
+        ``leaves``, 1, and ``bytes``, the packed size) and ``.dispatch``
+        (the call of the compiled decision program)."""
         n = self._round
         with span("fastpath.submit", round=n) as sp:
             with span("fastpath.stage"):
                 q = int(np.shape(inst["edge_mask"])[-1])
                 z = int(np.shape(inst["req_mask"])[-1])
                 bucket = self.bucket_for(q, z)
-                fn = self._get_fn(bucket)
-                staged = self._stage(inst, bucket)
+                lane, packed = self._stage(inst, bucket)
             sp.set_metadata(q=q, z=z, q_pad=bucket[0], z_pad=bucket[1])
-            with span("fastpath.transfer"):
-                dev = jax.device_put(staged)
+            with span("fastpath.transfer", leaves=1, bytes=packed.nbytes):
+                dev = jax.device_put(packed)
             with span("fastpath.dispatch"):
-                out = fn(dev, self._round_key())
+                out = lane.fn(dev, self._round_key())
             self._round += 1
         return out, z, n
 

@@ -13,7 +13,8 @@ import pytest
 from repro.core import InstanceConfig, generate_instance
 from repro.core.inference import policy_decide
 from repro.core.policy import PolicyConfig, corais_init
-from repro.serving.fastpath import (DecisionFastPath, SLOSpec, evaluate_slo,
+from repro.serving.fastpath import (DEFAULT_BUCKETS, DecisionFastPath,
+                                    PackedLayout, SLOSpec, evaluate_slo,
                                     pad_instance)
 
 CFG = PolicyConfig(d_model=32, ff_hidden=64, edge_layers=2, request_layers=1)
@@ -117,6 +118,118 @@ def test_fastpath_modes_and_donation_default(policy):
     # CPU resolves donate off automatically (jax can't donate on cpu)
     if jax.default_backend() == "cpu":
         assert fp_g.donate is False
+
+
+# -- packed staging ----------------------------------------------------------
+
+TEST_BUCKETS = ((8, 32), (16, 64))
+# float32 bit patterns a float path may corrupt: the two smallest
+# subnormals, a negative subnormal, -0.0, both infinities, the default NaN
+# and a NaN carrying a payload
+HOSTILE_F32 = np.array([0x00000001, 0x007FFFFF, 0x80000010, 0x80000000,
+                        0x7F800000, 0xFF800000, 0x7FC00000, 0x7FA12345],
+                       np.uint32).view(np.float32)
+
+
+def _hostile(inst):
+    """``inst`` with HOSTILE_F32 in its float leaves and the int32 limits
+    among its request sources."""
+    inst = {k: np.array(v, copy=True) for k, v in inst.items()}
+    n = len(HOSTILE_F32)
+    inst["req_size"][:n] = HOSTILE_F32
+    inst["workload"].reshape(-1)[:n] = HOSTILE_F32
+    inst["w"][0, :2] = HOSTILE_F32[2:4]
+    inst["ct"] = HOSTILE_F32[-1]
+    inst["req_src"][:2] = (np.iinfo(np.int32).min, np.iinfo(np.int32).max)
+    return inst
+
+
+def _assert_unpacks_to_padded(layout, buf, inst, bucket):
+    """The device unpack of ``buf`` is pad_instance(inst), leaf for leaf:
+    same dtype, shape and bits."""
+    got = jax.jit(layout.unpack)(jnp.asarray(buf))
+    want = pad_instance(inst, *bucket)
+    assert sorted(got) == sorted(want)
+    for k, w in want.items():
+        g, w = np.asarray(got[k]), np.asarray(w)
+        assert (g.dtype, g.shape) == (w.dtype, w.shape), k
+        np.testing.assert_array_equal(g.reshape(-1).view(np.uint8),
+                                      w.reshape(-1).view(np.uint8), err_msg=k)
+
+
+@pytest.mark.parametrize("bucket,extra", [(b, False) for b in
+                                          DEFAULT_BUCKETS + TEST_BUCKETS]
+                         + [((16, 64), True)])
+def test_packed_stage_is_bit_exact(policy, bucket, extra):
+    """Staging into the packed buffer and unpacking it on the device gives
+    exactly pad_instance's leaves; extra (schema-v3) keys travel too, in a
+    layout and program of their own."""
+    params, state = policy
+    fp = DecisionFastPath(params, state, CFG, buckets=(bucket,))
+    q, z = bucket[0] - 3, bucket[1] * 3 // 5
+    inst = _hostile(_inst(q, z, 7))
+    if extra:
+        inst.update(tier=np.linspace(0, 1, q, dtype=np.float32),
+                    req_priority=np.arange(z, dtype=np.int32),
+                    req_cached=np.arange(z) % 2 == 0)
+    lane, buf = fp._stage(inst, bucket)
+    assert buf.dtype == np.int32 and buf.ndim == 1
+    assert buf.nbytes == lane.layout.nbytes
+    assert all(off % 128 == 0 for off in lane.layout.offsets)
+    _assert_unpacks_to_padded(lane.layout, buf, inst, bucket)
+    if extra:
+        plain, _ = fp._stage(_inst(q, z, 8), bucket)
+        assert plain is not lane and plain.fn is not lane.fn
+        assert {"tier", "req_priority", "req_cached"} == (
+            {k for k, _, _ in lane.layout.leaves}
+            - {k for k, _, _ in plain.layout.leaves})
+
+
+def test_packed_stage_clears_stale_padding(policy):
+    """A small instance staged into the ping-pong buffer a larger one used
+    two rounds before decides as policy_decide does unpadded: nothing of
+    the larger round is left in the padding."""
+    params, state = policy
+    bucket = (16, 64)
+    fp = DecisionFastPath(params, state, CFG, buckets=(bucket,))
+    big, other, small = _inst(15, 60, 1), _inst(10, 40, 2), _inst(4, 9, 3)
+    fp.decide(big)    # slot 0
+    fp.decide(other)  # slot 1
+    got = fp.decide(small)  # slot 0 again
+    want = np.asarray(policy_decide(
+        None, params, state, jax.tree.map(jnp.asarray, small), CFG))
+    np.testing.assert_array_equal(got, want)
+    lane, = fp._lanes.values()
+    assert lane.slot == 1  # the small round went to slot 0
+    _assert_unpacks_to_padded(lane.layout, lane.bufs[0], small, bucket)
+
+
+def test_fastpath_transfers_one_of_two_buffers_per_bucket(policy,
+                                                          monkeypatch):
+    """Every round moves one int32 array to the device, and per bucket it
+    is one of the same two host arrays, in turn: no staging allocation per
+    round."""
+    params, state = policy
+    fp = DecisionFastPath(params, state, CFG, buckets=TEST_BUCKETS)
+    fp.warmup()
+    sent = []
+    put = jax.device_put
+    monkeypatch.setattr(jax, "device_put",
+                        lambda x, *a, **k: sent.append(x) or put(x, *a, **k))
+    insts = [_inst(5, 20, 0), _inst(12, 50, 1)] * 3 + [_inst(6, 25, 2)]
+    list(fp.stream(insts))
+    assert len(sent) == len(insts)
+    assert all(isinstance(x, np.ndarray) and x.dtype == np.int32
+               and x.ndim == 1 for x in sent)
+    lanes = {sig[0]: lane for sig, lane in fp._lanes.items()}
+    assert sorted(lanes) == sorted(TEST_BUCKETS)
+    small = [x for x, i in zip(sent, insts) if i["req_mask"].shape[0] < 32]
+    large = [x for x, i in zip(sent, insts) if i["req_mask"].shape[0] >= 32]
+    for bucket, xs in (((8, 32), small), ((16, 64), large)):
+        bufs = lanes[bucket].bufs
+        # warmup staged slot 0, so rounds start at slot 1
+        assert [x is bufs[(i + 1) % 2] for i, x in enumerate(xs)] == (
+            [True] * len(xs)), bucket
 
 
 # -- SLO ---------------------------------------------------------------------

@@ -15,7 +15,8 @@ from repro.core import InstanceConfig, generate_instance
 from repro.core.policy import PolicyConfig, corais_init
 from repro.kernels.policy_score import (policy_score_decode_fwd,
                                         policy_score_fwd)
-from repro.serving.fastpath import DecisionFastPath
+from repro.serving.fastpath import (DecisionFastPath, PackedLayout,
+                                    pad_instance)
 from repro.tracing import span
 
 CFG = PolicyConfig(d_model=32, ff_hidden=64, edge_layers=2, request_layers=1)
@@ -118,6 +119,18 @@ def test_fastpath_call_spans_carry_the_round(traced_decisions):
         assert (a["q"], a["z"]) == (inst["edge_mask"].shape[0],
                                     inst["req_mask"].shape[0])
         assert (a["q_pad"], a["z_pad"]) == bucket
+
+
+def test_fastpath_transfer_moves_one_packed_leaf(traced_decisions):
+    """Each transfer span says it moved one leaf, of the packed size of
+    its round's bucket layout."""
+    insts, spans = traced_decisions
+    args = [e[4] for e in spans if e[0] == "corais.fastpath.transfer"]
+    buckets = [(8, 32), (16, 64), (8, 32), (8, 32), (16, 64)]
+    assert args == [
+        {"leaves": 1,
+         "bytes": PackedLayout.of(pad_instance(inst, *bucket)).nbytes}
+        for inst, bucket in zip(insts, buckets)]
 
 
 # -- kernel names -------------------------------------------------------------
